@@ -57,7 +57,10 @@ def _parse_args(argv):
 def main() -> None:
     filters, out_path, block_rows, tiny = _parse_args(sys.argv[1:])
 
+    from repro.launch.cache import enable_compile_cache
+
     from . import paper_figs, roofline_report, spmd_step, stragglers
+    enable_compile_cache()
     if tiny:
         # CI smoke lane: identical dataflow + derived/parity gates, state
         # sizes shrunk so every selected benchmark finishes in seconds —

@@ -1007,6 +1007,45 @@ def exchange_packed(packed, ranges, shift_idx, block_idx, cfg: GossipConfig,
     return jax.lax.switch(idx, branches, packed)
 
 
+def _resident_blend(packed, pgrads, ext, ext_scales, ext_idx, ranges, lr,
+                    gate_scale, cfg: GossipConfig, acfg: ASGDConfig, spec):
+    """The resident-kernel blend of both packed engines: one received slot
+    ``ext`` (W, R, LANE) — int8 with ``ext_scales`` (W, nb) on the int8
+    wire — blended over partition ``ext_idx``'s row range.  Returns
+    (new_packed, gate (W,)).
+
+    Under an ambient mesh whose data axes shard the worker axis
+    (launch/mesh.py ambient_worker_mesh) the kernel runs per shard inside
+    shard_map_workers: GSPMD cannot partition a Mosaic kernel, and the
+    blend is communication-free per worker.  ``gate_scale`` is then
+    broadcast to (W,) so it splits with the workers (the same product
+    per gate)."""
+    from ..kernels.gossip_blend import gossip_blend_w_resident
+    from ..launch.mesh import ambient_worker_mesh, shard_map_workers
+
+    row_range = jnp.asarray(ranges, jnp.int32)[ext_idx]
+
+    def blend(packed, pgrads, ext, row_range, lr, gate_scale, *scales):
+        new_packed, gates = gossip_blend_w_resident(
+            packed, pgrads, ext[:, None], row_range, acfg.eps, lr=lr,
+            ext_scales=scales[0][:, None] if scales else None,
+            use_parzen=acfg.use_parzen, elastic=acfg.elastic,
+            elastic_alpha=acfg.elastic_alpha, block_rows=spec.block_rows,
+            psum_axes=cfg.gate_psum_axes or None, gate_scale=gate_scale)
+        return new_packed, gates[:, 0]
+
+    scales = () if ext_scales is None else (ext_scales,)
+    mesh = ambient_worker_mesh()
+    if mesh is None:
+        return blend(packed, pgrads, ext, row_range, lr, gate_scale, *scales)
+    lr = jnp.asarray(acfg.eps if lr is None else lr, jnp.float32)
+    gate_scale = jnp.broadcast_to(
+        jnp.float32(1.0) if gate_scale is None else gate_scale,
+        (packed.shape[0],))
+    return shard_map_workers(blend, mesh, replicated_argnums=(3, 4))(
+        packed, pgrads, ext, row_range, lr, gate_scale, *scales)
+
+
 def asgd_gossip_apply_packed(packed, pgrads, state: PackedGossipState, key,
                              cfg: GossipConfig, acfg: ASGDConfig, spec,
                              live=None):
@@ -1061,8 +1100,6 @@ def asgd_gossip_apply_packed(packed, pgrads, state: PackedGossipState, key,
 
     def gossip_branch(args):
         packed, pgrads, state = args
-        from ..kernels.gossip_blend import gossip_blend_w_resident
-
         if wire == "int8":
             sent, sent_scales = exchange_packed(
                 packed, ranges, shift_idx, block_idx, cfg,
@@ -1086,15 +1123,9 @@ def asgd_gossip_apply_packed(packed, pgrads, state: PackedGossipState, key,
             # rounds ago); delay == 1 keeps the historical single slot
             ext, ext_scales, ext_idx, ext_live = _fifo_head(state, stacked)
             valid = staleness_valid(state.step, cfg)
-        row_range = jnp.asarray(ranges, jnp.int32)[ext_idx]
-        new_packed, gates = gossip_blend_w_resident(
-            packed, pgrads, ext[:, None], row_range, acfg.eps,
-            ext_scales=None if ext_scales is None else ext_scales[:, None],
-            use_parzen=acfg.use_parzen, elastic=acfg.elastic,
-            elastic_alpha=acfg.elastic_alpha, block_rows=spec.block_rows,
-            psum_axes=cfg.gate_psum_axes or None,
-            gate_scale=combine_gate_scale(valid, ext_live, live))
-        gate = gates[:, 0]
+        new_packed, gate = _resident_blend(
+            packed, pgrads, ext, ext_scales, ext_idx, ranges, None,
+            combine_gate_scale(valid, ext_live, live), cfg, acfg, spec)
         new_state = _fifo_push(state, sent, sent_scales, block_idx,
                                stacked, sent_live=sent_live)
         return new_packed, new_state, {"gate": gate,
@@ -1173,8 +1204,6 @@ def consume_exchange_packed(packed, pgrads, state: PackedGossipState, sent,
     liveness; the FIFO head's recorded validity and the current mask both
     close the gates.  Returns (new_packed, new_state, metrics) with the
     engine metrics contract."""
-    from ..kernels.gossip_blend import gossip_blend_w_resident
-
     live = _resolve_live(state.buf_live is not None, live, packed.shape[0],
                          "consume_exchange_packed")
     if live is not None:
@@ -1184,16 +1213,10 @@ def consume_exchange_packed(packed, pgrads, state: PackedGossipState, sent,
     stacked = fifo_depth(cfg, pipelined=True) >= 2
     ext, ext_scales, ext_idx, ext_live = _fifo_head(state, stacked)
     valid = staleness_valid(state.step, cfg, extra=1)
-    ranges = packed_row_ranges(spec, cfg)
-    row_range = jnp.asarray(ranges, jnp.int32)[ext_idx]
-    new_packed, gates = gossip_blend_w_resident(
-        packed, pgrads, ext[:, None], row_range, acfg.eps, lr=lr,
-        ext_scales=None if ext_scales is None else ext_scales[:, None],
-        use_parzen=acfg.use_parzen, elastic=acfg.elastic,
-        elastic_alpha=acfg.elastic_alpha, block_rows=spec.block_rows,
-        psum_axes=cfg.gate_psum_axes or None,
-        gate_scale=combine_gate_scale(valid, ext_live, live))
-    gate = gates[:, 0]
+    new_packed, gate = _resident_blend(
+        packed, pgrads, ext, ext_scales, ext_idx,
+        packed_row_ranges(spec, cfg), lr,
+        combine_gate_scale(valid, ext_live, live), cfg, acfg, spec)
     new_state = _fifo_push(state, sent, sent_scales, block_idx, stacked,
                            sent_live=sent_live)
     return new_packed, new_state, {"gate": gate, "n_good": jnp.sum(gate)}

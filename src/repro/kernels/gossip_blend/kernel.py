@@ -27,7 +27,12 @@ round with N receive buffers.  HBM traffic per round, in full-state sweeps:
 Grid: 1-D over row blocks of the state viewed as (R, LANE) with LANE=512
 f32 lanes; the P axis lives entirely inside each block (states are blended
 P-at-a-time, P is small — the paper's N receive buffers, typically <= 8).
-Reductions accumulate in a (P, 3) VMEM output block.
+Pass 1 accumulates lane-dense partial sums: one ``(s, LANE)`` f32 tile per
+reduction term (s = gcd(block_rows, 8) sublanes), added elementwise every
+grid step, so the kernel never reduces across lanes and never stores a
+1-D vector (Mosaic rejects both layouts); the jitted wrapper sums each
+tile to its scalar.  Per-worker scalars of pass 2 (gates, 1/denominator,
+lr, int8 scales) live in SMEM.
 
 Worker-batched variants (``*_w_pallas``, DESIGN.md §6): the SPMD gossip path
 (core/gossip.py) holds W_local worker replicas per shard, each with its own
@@ -67,6 +72,7 @@ accumulator in the wrapper, outside the kernel).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +80,45 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import LANE, resolve_interpret
+
+
+def _acc_sublanes(block_rows: int) -> int:
+    """Sublanes of one pass-1 partial-sum tile: the full 8-row f32 tile
+    when block_rows allows, else the largest divisor of 8 that fits."""
+    return math.gcd(block_rows, 8)
+
+
+def _fold_rows(x):
+    """(br, LANE) -> (s, LANE): sum row groups elementwise (VPU adds only)."""
+    br = x.shape[0]
+    s = _acc_sublanes(br)
+    return jnp.sum(x.reshape(br // s, s, LANE), axis=0)
+
+
+def _accumulate_terms(acc_ref, w, dw, exts):
+    """Add one row block's reduction terms to the ``(1, 2P+1, s, LANE)``
+    partial-sum block: rows [0, P) <dw, w - ext_p>, rows [P, 2P)
+    ||ext_p||^2, row 2P ||dw||^2.  ``exts`` is a sequence of P (br, LANE)
+    externals."""
+    p = len(exts)
+    for k, ext in enumerate(exts):
+        acc_ref[0, k] += _fold_rows(dw * (w - ext))
+        acc_ref[0, p + k] += _fold_rows(ext * ext)
+    acc_ref[0, 2 * p] += _fold_rows(dw * dw)
+
+
+def _partials_shape(lead: tuple, p: int, block_rows: int) -> tuple:
+    return lead + (2 * p + 1, _acc_sublanes(block_rows), LANE)
+
+
+def _terms_from_partials(partials):
+    """(..., 2P+1, s, LANE) partial sums -> (..., P, 3) accumulator
+    [<dw, w - ext_p>, ||ext_p||^2, ||dw||^2] (||dw||^2 repeated per p)."""
+    sums = jnp.sum(partials, axis=(-2, -1))
+    p = (sums.shape[-1] - 1) // 2
+    dot, sq_ext = sums[..., :p], sums[..., p:2 * p]
+    sq_dw = jnp.broadcast_to(sums[..., 2 * p:], dot.shape)
+    return jnp.stack([dot, sq_ext, sq_dw], axis=-1)
 
 
 def _reduce_kernel(w_ref, dw_ref, ext_ref, acc_ref):
@@ -86,12 +131,7 @@ def _reduce_kernel(w_ref, dw_ref, ext_ref, acc_ref):
     w = w_ref[...].astype(jnp.float32)          # (br, LANE)
     dw = dw_ref[...].astype(jnp.float32)        # (br, LANE)
     ext = ext_ref[...].astype(jnp.float32)      # (P, br, LANE)
-    dot = jnp.sum(dw[None] * (w[None] - ext), axis=(1, 2))   # (P,)
-    sq_ext = jnp.sum(ext * ext, axis=(1, 2))                 # (P,)
-    sq_dw = jnp.sum(dw * dw)                                 # shared scalar
-    acc_ref[:, 0] += dot
-    acc_ref[:, 1] += sq_ext
-    acc_ref[:, 2] += sq_dw      # replicated across P rows (read row 0)
+    _accumulate_terms(acc_ref, w, dw, [ext[k] for k in range(ext.shape[0])])
 
 
 def _apply_kernel(w_ref, dw_ref, ext_ref, gates_ref, inv_denom_ref, out_ref,
@@ -124,16 +164,17 @@ def gossip_reduce_pallas(w2d, dw2d, ext3d, *, block_rows=64, interpret=None):
     p = ext3d.shape[0]
     grid = (r // block_rows,)
     spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    acc = pl.pallas_call(
+    acc_shape = _partials_shape((1,), p, block_rows)
+    partials = pl.pallas_call(
         _reduce_kernel,
         grid=grid,
         in_specs=[spec, spec,
                   pl.BlockSpec((p, block_rows, LANE), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((p, 3), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, 3), jnp.float32),
+        out_specs=pl.BlockSpec(acc_shape, lambda i: (0, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
         interpret=resolve_interpret(interpret),
     )(w2d, dw2d, ext3d)
-    return acc
+    return _terms_from_partials(partials[0])
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -180,22 +221,33 @@ def _reduce_w_kernel(*refs, has_mask):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = w_ref[...][0].astype(jnp.float32)       # (br, LANE)
-    dw = dw_ref[...][0].astype(jnp.float32)     # (br, LANE)
-    ext = ext_ref[...][0].astype(jnp.float32)   # (P, br, LANE)
+    w = w_ref[0].astype(jnp.float32)            # (br, LANE)
+    dw = dw_ref[0].astype(jnp.float32)          # (br, LANE)
+    exts = [ext_ref[0, k].astype(jnp.float32)   # P x (br, LANE)
+            for k in range(ext_ref.shape[1])]
     if has_mask:
         # restrict every reduction term to the exchanged partition: masking
         # dw kills off-partition <dw, w-ext> and ||dw||^2 contributions,
         # masking ext kills off-partition ||ext||^2 (m in {0,1}, m^2 == m)
         m = mask_ref[...].astype(jnp.float32)   # (br, LANE), worker-shared
         dw = dw * m
-        ext = ext * m[None]
-    dot = jnp.sum(dw[None] * (w[None] - ext), axis=(1, 2))   # (P,)
-    sq_ext = jnp.sum(ext * ext, axis=(1, 2))                 # (P,)
-    sq_dw = jnp.sum(dw * dw)                                 # shared scalar
-    acc_ref[0, :, 0] += dot
-    acc_ref[0, :, 1] += sq_ext
-    acc_ref[0, :, 2] += sq_dw   # replicated across P rows (read row 0)
+        exts = [ext * m for ext in exts]
+    _accumulate_terms(acc_ref, w, dw, exts)
+
+
+def _gated_sum(w, exts, gate):
+    """w + sum_p gate(p) * ext_p — the eq.-(6) numerator; ``gate(p)`` reads
+    the p-th admission gate as a scalar."""
+    acc = gate(0) * exts[0]
+    for k in range(1, len(exts)):
+        acc = acc + gate(k) * exts[k]
+    return w + acc
+
+
+# whole-array SMEM operand: the per-worker scalars of pass 2, read with the
+# worker grid index (a (1, P) or (1, 1) VMEM block would break the TPU
+# (8, 128) block rule)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _apply_w_kernel(*refs, eps, elastic, elastic_alpha, has_mask):
@@ -203,12 +255,12 @@ def _apply_w_kernel(*refs, eps, elastic, elastic_alpha, has_mask):
         w_ref, dw_ref, ext_ref, gates_ref, inv_ref, mask_ref, out_ref = refs
     else:
         w_ref, dw_ref, ext_ref, gates_ref, inv_ref, out_ref = refs
-    w = w_ref[...][0].astype(jnp.float32)       # (br, LANE)
-    dw = dw_ref[...][0].astype(jnp.float32)
-    ext = ext_ref[...][0].astype(jnp.float32)   # (P, br, LANE)
-    g = gates_ref[...][0]                       # (P,)
-    inv_denom = inv_ref[...][0, 0]
-    mean = inv_denom * (w + jnp.sum(g[:, None, None] * ext, axis=0))
+    wi = pl.program_id(0)
+    w = w_ref[0].astype(jnp.float32)            # (br, LANE)
+    dw = dw_ref[0].astype(jnp.float32)
+    exts = [ext_ref[0, k].astype(jnp.float32)   # P x (br, LANE)
+            for k in range(ext_ref.shape[1])]
+    mean = inv_ref[wi] * _gated_sum(w, exts, lambda k: gates_ref[wi, k])
     attraction = w - mean
     if has_mask:
         # off-partition positions take the plain SGD step (the attraction is
@@ -218,7 +270,7 @@ def _apply_w_kernel(*refs, eps, elastic, elastic_alpha, has_mask):
         out = (w - eps * dw) - elastic_alpha * attraction
     else:
         out = w - eps * (attraction + dw)
-    out_ref[...] = out[None].astype(out_ref.dtype)
+    out_ref[0] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -244,14 +296,17 @@ def gossip_reduce_w_pallas(w3d, dw3d, ext4d, mask2d=None, *, block_rows=64,
         in_specs.append(pl.BlockSpec((block_rows, LANE),
                                      lambda wi, i: (i, 0)))
         operands.append(mask2d)
-    return pl.pallas_call(
+    acc_shape = _partials_shape((wn,), p, block_rows)
+    partials = pl.pallas_call(
         functools.partial(_reduce_w_kernel, has_mask=mask2d is not None),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, p, 3), lambda wi, i: (wi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((wn, p, 3), jnp.float32),
+        out_specs=pl.BlockSpec((1,) + acc_shape[1:],
+                               lambda wi, i: (wi, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
         interpret=resolve_interpret(interpret),
     )(*operands)
+    return _terms_from_partials(partials)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -271,11 +326,9 @@ def gossip_apply_w_pallas(w3d, dw3d, ext4d, gates, inv_denom, mask2d=None, *,
     spec_s = pl.BlockSpec((1, block_rows, LANE), lambda wi, i: (wi, i, 0))
     spec_e = pl.BlockSpec((1, p, block_rows, LANE),
                           lambda wi, i: (wi, 0, i, 0))
-    in_specs = [spec_s, spec_s, spec_e,
-                pl.BlockSpec((1, p), lambda wi, i: (wi, 0)),
-                pl.BlockSpec((1, 1), lambda wi, i: (wi, 0))]
-    operands = [w3d, dw3d, ext4d, gates,
-                jnp.asarray(inv_denom, jnp.float32).reshape(wn, 1)]
+    in_specs = [spec_s, spec_s, spec_e, _SMEM, _SMEM]
+    operands = [w3d, dw3d, ext4d, gates.astype(jnp.float32),
+                jnp.asarray(inv_denom, jnp.float32).reshape(wn)]
     if mask2d is not None:
         in_specs.append(pl.BlockSpec((block_rows, LANE),
                                      lambda wi, i: (i, 0)))
@@ -305,11 +358,28 @@ def _row_range_mask(rr_ref, block_idx, block_rows):
     return ((rows >= rr_ref[0]) & (rows < rr_ref[1])).astype(jnp.float32)
 
 
+def _resident_exts(ext_ref, scales_ref):
+    """The P externals of one row block as f32 (br, LANE) values.  With
+    int8 wire scales (the whole (W, P, R // block_rows) array in SMEM: one
+    f32 per external per row block — the quantization tile == the kernel
+    grid block) the dequantization is fused here, so the external never
+    materializes in float in HBM."""
+    exts = []
+    for k in range(ext_ref.shape[1]):
+        ext = ext_ref[0, k].astype(jnp.float32)
+        if scales_ref is not None:
+            wi, i = pl.program_id(0), pl.program_id(1)
+            ext = ext * scales_ref[wi, k, i]
+        exts.append(ext)
+    return exts
+
+
 def _reduce_w_resident_kernel(*refs, block_rows, has_scales):
     if has_scales:
         rr_ref, w_ref, dw_ref, ext_ref, scales_ref, acc_ref = refs
     else:
         rr_ref, w_ref, dw_ref, ext_ref, acc_ref = refs
+        scales_ref = None
     i = pl.program_id(1)        # row-block index (innermost grid dim)
 
     @pl.when(i == 0)
@@ -317,21 +387,10 @@ def _reduce_w_resident_kernel(*refs, block_rows, has_scales):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     m = _row_range_mask(rr_ref, i, block_rows)
-    w = w_ref[...][0].astype(jnp.float32)            # (br, LANE)
-    dw = dw_ref[...][0].astype(jnp.float32) * m
-    ext = ext_ref[...][0].astype(jnp.float32)        # (P, br, LANE)
-    if has_scales:
-        # fused int8-wire dequantization: one f32 scale per external per
-        # row block (the quantization tile == the kernel grid block), so
-        # the external never materializes in float in HBM
-        ext = ext * scales_ref[...][0, :, 0][:, None, None]
-    ext = ext * m[None]
-    dot = jnp.sum(dw[None] * (w[None] - ext), axis=(1, 2))   # (P,)
-    sq_ext = jnp.sum(ext * ext, axis=(1, 2))                 # (P,)
-    sq_dw = jnp.sum(dw * dw)                                 # shared scalar
-    acc_ref[0, :, 0] += dot
-    acc_ref[0, :, 1] += sq_ext
-    acc_ref[0, :, 2] += sq_dw   # replicated across P rows (read row 0)
+    w = w_ref[0].astype(jnp.float32)                 # (br, LANE)
+    dw = dw_ref[0].astype(jnp.float32) * m
+    exts = [ext * m for ext in _resident_exts(ext_ref, scales_ref)]
+    _accumulate_terms(acc_ref, w, dw, exts)
 
 
 def _apply_w_resident_kernel(*refs, elastic, elastic_alpha, block_rows,
@@ -342,20 +401,17 @@ def _apply_w_resident_kernel(*refs, elastic, elastic_alpha, block_rows,
     else:
         (rr_ref, w_ref, dw_ref, ext_ref, gates_ref, inv_ref, lr_ref,
          out_ref) = refs
-    i = pl.program_id(1)
+        scales_ref = None
+    wi, i = pl.program_id(0), pl.program_id(1)
     m = _row_range_mask(rr_ref, i, block_rows)
-    w = w_ref[...][0].astype(jnp.float32)            # (br, LANE)
-    dw = dw_ref[...][0].astype(jnp.float32)
-    ext = ext_ref[...][0].astype(jnp.float32)        # (P, br, LANE)
-    if has_scales:
-        ext = ext * scales_ref[...][0, :, 0][:, None, None]
-    g = gates_ref[...][0]                            # (P,)
-    inv_denom = inv_ref[...][0, 0]
+    w = w_ref[0].astype(jnp.float32)                 # (br, LANE)
+    dw = dw_ref[0].astype(jnp.float32)
+    exts = _resident_exts(ext_ref, scales_ref)
     # lr is a RUNTIME operand (one f32 scalar shared by the whole grid):
     # the eq.-1 local update w - lr*dw is applied in-register in the same
     # sweep as the blend, and an lr schedule never forces a recompile
-    lr = lr_ref[...][0, 0]
-    mean = inv_denom * (w + jnp.sum(g[:, None, None] * ext, axis=0))
+    lr = lr_ref[0]
+    mean = inv_ref[wi] * _gated_sum(w, exts, lambda k: gates_ref[wi, k])
     # off-partition positions take the plain SGD step (the attraction is
     # defined only on the exchanged row range)
     attraction = (w - mean) * m
@@ -363,7 +419,7 @@ def _apply_w_resident_kernel(*refs, elastic, elastic_alpha, block_rows,
         out = (w - lr * dw) - elastic_alpha * attraction
     else:
         out = w - lr * (attraction + dw)
-    out_ref[...] = out[None].astype(out_ref.dtype)
+    out_ref[0] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -390,21 +446,24 @@ def gossip_reduce_w_resident_pallas(row_range, w3d, dw3d, ext4d,
     ]
     operands = [w3d, dw3d, ext4d]
     if ext_scales is not None:
-        in_specs.append(pl.BlockSpec((1, p, 1), lambda wi, i, rr: (wi, 0, i)))
+        in_specs.append(_SMEM)
         operands.append(ext_scales.astype(jnp.float32))
+    acc_shape = _partials_shape((wn,), p, block_rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(wn, r // block_rows),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, p, 3), lambda wi, i, rr: (wi, 0, 0)),
+        out_specs=pl.BlockSpec((1,) + acc_shape[1:],
+                               lambda wi, i, rr: (wi, 0, 0, 0)),
     )
-    return pl.pallas_call(
+    partials = pl.pallas_call(
         functools.partial(_reduce_w_resident_kernel, block_rows=block_rows,
                           has_scales=ext_scales is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((wn, p, 3), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
         interpret=resolve_interpret(interpret),
     )(row_range.astype(jnp.int32), *operands)
+    return _terms_from_partials(partials)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -431,15 +490,12 @@ def gossip_apply_w_resident_pallas(row_range, w3d, dw3d, ext4d, gates,
     ]
     operands = [w3d, dw3d, ext4d]
     if ext_scales is not None:
-        in_specs.append(pl.BlockSpec((1, p, 1), lambda wi, i, rr: (wi, 0, i)))
+        in_specs.append(_SMEM)
         operands.append(ext_scales.astype(jnp.float32))
-    in_specs += [
-        pl.BlockSpec((1, p), lambda wi, i, rr: (wi, 0)),
-        pl.BlockSpec((1, 1), lambda wi, i, rr: (wi, 0)),
-        pl.BlockSpec((1, 1), lambda wi, i, rr: (0, 0)),
-    ]
-    operands += [gates, jnp.asarray(inv_denom, jnp.float32).reshape(wn, 1),
-                 jnp.asarray(lr, jnp.float32).reshape(1, 1)]
+    in_specs += [_SMEM, _SMEM, _SMEM]
+    operands += [gates.astype(jnp.float32),
+                 jnp.asarray(inv_denom, jnp.float32).reshape(wn),
+                 jnp.asarray(lr, jnp.float32).reshape(1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(wn, r // block_rows),

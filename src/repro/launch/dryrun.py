@@ -24,7 +24,7 @@ from ..core.gossip import GossipConfig
 from . import steps as ST
 from .hlo_analysis import (RooflineTerms, collective_bytes_from_hlo,
                            model_flops)
-from .mesh import make_production_mesh, mesh_context
+from .mesh import make_production_mesh
 
 ARTIFACT_DIR = pathlib.Path(__file__).resolve().parents[3] / \
     "launch_artifacts"
@@ -34,12 +34,10 @@ def _compile_and_cost(cfg, shape, mesh, gcfg, algo, engine="pytree"):
     """(compiled, flops, bytes, collective_dict) for one model config."""
     fn, specs = ST.step_and_args(cfg, shape, mesh, gcfg, algo=algo,
                                  engine=engine)
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jax.jit(fn).lower(*specs.values())
         compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbytes = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes_from_hlo(compiled.as_text())

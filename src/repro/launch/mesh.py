@@ -12,45 +12,27 @@ tests and benches see the single real device).
 """
 from __future__ import annotations
 
-import contextlib
+import math
 
 import jax
-
-
-def _auto_mesh(shape, axes):
-    """jax.make_mesh across jax versions: AxisType appeared post-0.4.x;
-    0.4.x meshes behave as Auto already."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """jax.sharding.set_mesh if this jax has it, else a no-op context.
-
-    All launch-path shardings are explicit NamedShardings, so the ambient
-    mesh is only required by newer-jax explicit-axis features.
-    """
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return contextlib.nullcontext()
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_host_mesh(data: int = 2, model: int = 2):
-    """Small mesh over however many (fake) devices the host exposes —
-    used by tests and the smoke dry-run."""
-    n = len(jax.devices())
-    data = min(data, max(1, n // model))
-    return _auto_mesh((data, model), ("data", "model"))
+def make_host_mesh(data: int = 2, model: int = 2, *, devices=None):
+    """(data, model) mesh over the host's devices (``devices``, default
+    ``jax.devices()``): the trainer's (device count, 1) worker mesh, and
+    the small meshes of the tests and the smoke dry-run."""
+    devices = jax.devices() if devices is None else list(devices)
+    data = min(data, max(1, len(devices) // model))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=devices[:data * model])
 
 
 def data_axes(mesh) -> tuple:
@@ -59,8 +41,22 @@ def data_axes(mesh) -> tuple:
 
 
 def n_worker_groups(mesh) -> int:
-    import math
     return math.prod(mesh.shape[a] for a in data_axes(mesh))
+
+
+def ambient_worker_mesh():
+    """The ambient mesh (``jax.set_mesh``) when its data axes split the
+    worker axis over more than one device, else None — also None inside a
+    shard_map region whose data axes are already manual.  A Mosaic kernel
+    cannot be partitioned by GSPMD, so the packed engines run their kernel
+    through :func:`shard_map_workers` on this mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return None
+    wa = data_axes(mesh)
+    if not wa or any(a in mesh.manual_axes for a in wa):
+        return None
+    return mesh if n_worker_groups(mesh) > 1 else None
 
 
 def local_worker_count(mesh, n_workers: int | None = None) -> int:
@@ -101,11 +97,9 @@ def shard_map_workers(fn, mesh, *, replicated_argnums=()):
     only when the non-worker dims are ALSO manually sharded
     (GossipConfig.gate_psum_axes).
 
-    check_rep is disabled: pallas_call inside shard_map defeats jax's
+    check_vma is disabled: pallas_call inside shard_map defeats jax's
     replication inference.
     """
-    from jax.experimental.shard_map import shard_map
-
     wa = data_axes(mesh)
     if not wa:
         raise ValueError(
@@ -118,8 +112,8 @@ def shard_map_workers(fn, mesh, *, replicated_argnums=()):
     def wrapped(*args):
         in_specs = tuple(rep if i in repl else split
                          for i in range(len(args)))
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=split,
-                         check_rep=False)(*args)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=split, check_vma=False)(*args)
     return wrapped
 
 
@@ -165,8 +159,6 @@ def _region_ctx(mesh, spec, cfg, n_workers):
     """Shared setup of the manual-region gossip functions: worker-axis
     name(s), shard count, local worker count, static row ranges, resolved
     wire format, and the split/replicated PartitionSpecs."""
-    import math
-
     from ..core.gossip import packed_row_ranges, resolved_wire_format
 
     wa = data_axes(mesh)
@@ -291,7 +283,6 @@ def shard_map_gossip_round(mesh, spec, cfg, acfg, *, n_workers=None,
     blending it.
     """
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     from ..core.gossip import mask_live_rows
 
@@ -371,16 +362,16 @@ def shard_map_gossip_round(mesh, spec, cfg, acfg, *, n_workers=None,
     if elastic:
         # buf_live + live ride as trailing split operands; sent_live as a
         # trailing split output
-        return shard_map(
+        return jax.shard_map(
             round_fn, mesh=mesh,
             in_specs=(split,) * n_split_in + (rep,) * 4 + (split,) * 2,
             out_specs=(split,) * (n_out + 1),
-            check_rep=False)
-    return shard_map(
+            check_vma=False)
+    return jax.shard_map(
         round_fn, mesh=mesh,
         in_specs=(split,) * n_split_in + (rep,) * 4,
         out_specs=(split,) * n_out,
-        check_rep=False)
+        check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -407,8 +398,6 @@ def shard_map_initiate_exchange(mesh, spec, cfg, *, n_workers=None,
     elastic=True appends a split ``live`` operand and a trailing
     ``sent_live`` output: dead peers' payload rows leave the region as
     eq.-3 zeros (the masked ppermute payload is dropped, DESIGN.md §8)."""
-    from jax.experimental.shard_map import shard_map
-
     from ..core.gossip import mask_live_rows
 
     axis_name, n_shards, w_local, ranges, wire, split, rep = _region_ctx(
@@ -432,11 +421,11 @@ def shard_map_initiate_exchange(mesh, spec, cfg, *, n_workers=None,
         return mask_live_rows(out, sent_live), sent_live
 
     n_out = (2 if wire == "int8" else 1) + (1 if elastic else 0)
-    return shard_map(
+    return jax.shard_map(
         initiate, mesh=mesh,
         in_specs=(split,) + (rep,) * 2 + ((split,) if elastic else ()),
         out_specs=(split,) * n_out if n_out > 1 else split,
-        check_rep=False)
+        check_vma=False)
 
 
 def shard_map_consume_blend(mesh, spec, cfg, acfg, *, n_workers=None,
@@ -457,7 +446,6 @@ def shard_map_consume_blend(mesh, spec, cfg, acfg, *, n_workers=None,
     gate_scale path as the scalar guard, and dead workers' local steps
     are masked (DESIGN.md §8)."""
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     from ..core.gossip import mask_live_rows
 
@@ -492,12 +480,12 @@ def shard_map_consume_blend(mesh, spec, cfg, acfg, *, n_workers=None,
                                  lives=lives)
         n_split_in = 3   # packed, pgrads, ext
 
-    return shard_map(
+    return jax.shard_map(
         consume, mesh=mesh,
         in_specs=(split,) * n_split_in + (rep,) * 2
         + ((split,) * 2 if elastic else ()),  # ext_idx, step[, lives]
         out_specs=(split, split),
-        check_rep=False)
+        check_vma=False)
 
 
 def shard_map_pipelined_round(mesh, spec, cfg, acfg, *, n_workers=None,
@@ -526,7 +514,6 @@ def shard_map_pipelined_round(mesh, spec, cfg, acfg, *, n_workers=None,
     ``sent_live`` recording the validity of the payload launched this
     round."""
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     from ..core.gossip import mask_live_rows
 
@@ -586,13 +573,13 @@ def shard_map_pipelined_round(mesh, spec, cfg, acfg, *, n_workers=None,
         n_split_in, n_out = 3, 3
 
     if elastic:
-        return shard_map(
+        return jax.shard_map(
             round_fn, mesh=mesh,
             in_specs=(split,) * n_split_in + (rep,) * 4 + (split,) * 2,
             out_specs=(split,) * (n_out + 1),
-            check_rep=False)
-    return shard_map(
+            check_vma=False)
+    return jax.shard_map(
         round_fn, mesh=mesh,
         in_specs=(split,) * n_split_in + (rep,) * 4,
         out_specs=(split,) * n_out,
-        check_rep=False)
+        check_vma=False)

@@ -142,6 +142,18 @@ def _worker_split(mesh):
     return jax.sharding.PartitionSpec(wa if len(wa) > 1 else wa[0])
 
 
+def place_workers(tree, mesh, *, axis: int = 0):
+    """device_put every leaf of ``tree`` with its ``axis`` (the worker
+    axis) split over the mesh's data axes; leaves of lower rank (round
+    counters, partition ids) are replicated."""
+    split = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(*(None,) * axis,
+                                         *_worker_split(mesh)))
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    return jax.tree.map(
+        lambda x: jax.device_put(x, split if x.ndim > axis else rep), tree)
+
+
 def packed_params_struct(cfg: ModelConfig, mesh, gcfg: GossipConfig,
                          spec=None):
     """ShapeDtypeStruct of the resident (W, rows, LANE) f32 ensemble,

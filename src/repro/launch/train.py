@@ -7,13 +7,21 @@ Examples (CPU-host scale):
   PYTHONPATH=src python -m repro.launch.train --arch granite-moe-1b-a400m \\
       --reduced --steps 20 --algo sync
 
-On a real TPU slice, drop --reduced and pass --mesh single|multi to shard
-over the production mesh (the same code path the dry-run compiles).
+Mesh: the trainer builds a (data, model=1) mesh over the devices it is
+given (all of ``jax.devices()`` by default), with data = gcd(--workers,
+device count).  The worker axis of the params, the gossip FIFO, the inner
+optimizer state and the batch is split over ``data``, so each device holds
+W / data worker replicas; on one device every replica shares it.  The
+packed engines run their Pallas kernel per device (shard_map over
+``data``) and the gossip exchange lowers to a collective-permute.  Drop
+--reduced for the published widths.
 """
 from __future__ import annotations
 
 import argparse
+import math
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -23,22 +31,22 @@ from ..checkpoint import (load_checkpoint, load_checkpoint_packed,
                           save_checkpoint, save_checkpoint_packed)
 from ..configs.registry import get_arch
 from ..core.asgd import ASGDConfig
-from ..core.gossip import (GossipConfig, final_average, init_gossip_state,
+from ..core.gossip import (GossipConfig, init_gossip_state,
                            init_packed_gossip_state,
                            init_pipelined_gossip_state, leaf_groups)
-from ..core.packing import pack_spec_w, pack_w, unpack_w
+from ..core.packing import pack_spec_w, pack_w
 from ..data.synthetic import lm_batch_iterator
 from ..models import model as M
-from .steps import make_train_step
+from .cache import enable_compile_cache
+from .mesh import make_host_mesh
+from .steps import init_inner_state, make_train_step, place_workers
 
 
-def stack_batches(it, workers):
-    """Pull one host batch per worker and stack along the W axis."""
-    bs = [next(it) for _ in range(workers)]
-    return {k: jnp.stack([jnp.asarray(b[k]) for b in bs]) for k in bs[0]}
-
-
-def main(argv=None):
+def setup(argv=None, *, devices=None):
+    """Parse ``argv`` and build one training run: the mesh over
+    ``devices`` (default ``jax.devices()``), the initial state placed on
+    it, and the jitted step.  :func:`train` runs it; :func:`main` is the
+    two together."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -85,7 +93,7 @@ def main(argv=None):
     ap.add_argument("--packed-resident", action="store_true",
                     help="carry the packed (W, R, LANE) ensemble across "
                          "steps (DESIGN.md §6): gossip exchange + blend on "
-                         "packed rows; unpack only at checkpoint/final "
+                         "packed rows; unpack only at checkpoint "
                          "boundaries")
     ap.add_argument("--pipelined", action="store_true",
                     help="pipeline the gossip round (DESIGN.md §7, implies "
@@ -126,7 +134,6 @@ def main(argv=None):
         partial_blocks=args.partial_blocks, delay=args.delay,
         wire_format=wire_format, payload_dtype=payload_dtype)
     acfg = ASGDConfig(eps=args.eps, elastic=args.elastic_blend)
-    from .steps import init_inner_state
     spec = None
     if args.pipelined:
         args.packed_resident = True
@@ -141,8 +148,8 @@ def main(argv=None):
         schedule = _mk_sched(args.lr_schedule, args.eps,
                              warmup=args.warmup, total=args.steps)
     if args.packed_resident:
-        # pack ONCE at init; the ensemble stays packed until checkpoint /
-        # final-aggregate boundaries (DESIGN.md §6)
+        # pack ONCE at init; the ensemble stays packed until checkpoint
+        # boundaries (DESIGN.md §6)
         spec = pack_spec_w(
             wparams, block_rows=gcfg.fused_block_rows,
             groups=leaf_groups(wparams, gcfg.partial_blocks),
@@ -180,13 +187,24 @@ def main(argv=None):
                                     resize_workers=args.elastic)
             print(f"restored step={int(state['step'])} from {args.restore}")
 
+    devices = jax.devices() if devices is None else list(devices)
+    mesh = make_host_mesh(data=math.gcd(W, len(devices)), model=1,
+                          devices=devices)
+    # stacked packed FIFOs (depth >= 2) carry the worker axis second
+    fifo_axis = state["gossip"].buf.ndim - 3 if args.packed_resident else 0
+    state = {"params": place_workers(state["params"], mesh),
+             "gossip": place_workers(state["gossip"], mesh, axis=fifo_axis),
+             "opt": place_workers(state["opt"], mesh),
+             "step": state["step"]}
+
     step_fn = jax.jit(make_train_step(
         cfg, algo=args.algo, gcfg=gcfg, acfg=acfg, inner=args.inner,
-        packed_resident=args.packed_resident, pack_spec=spec,
-        pipelined=args.pipelined, lr_schedule=schedule))
+        spmd_axes="data", packed_resident=args.packed_resident,
+        pack_spec=spec, pipelined=args.pipelined, lr_schedule=schedule))
     # the CLI trainer drives a fully-live fleet; a launcher that detects
     # real churn would flip entries of this mask per round (DESIGN.md §8)
-    live_args = ((jnp.ones((W,), jnp.float32),) if args.elastic else ())
+    live_args = ((place_workers(jnp.ones((W,), jnp.float32), mesh),)
+                 if args.elastic else ())
     its = [lm_batch_iterator(
         args.seed * 1000 + w, args.batch, args.seq, cfg.vocab,
         frontend=cfg.frontend, d_model=cfg.d_model,
@@ -195,31 +213,36 @@ def main(argv=None):
 
     def next_wbatch():
         bs = [next(it) for it in its]
-        return {k: jnp.stack([jnp.asarray(b[k]) for b in bs])
-                for k in bs[0]}
+        return place_workers({k: np.stack([b[k] for b in bs])
+                              for k in bs[0]}, mesh)
 
+    return SimpleNamespace(args=args, cfg=cfg, spec=spec, mesh=mesh,
+                           state=state, step_fn=step_fn, key=key,
+                           live_args=live_args, next_wbatch=next_wbatch)
+
+
+def train(run):
+    """Run ``run`` (from :func:`setup`) to ``--steps`` under its mesh;
+    return the per-step losses."""
+    args, state = run.args, run.state
     t0 = time.time()
     losses = []
-    for step in range(int(state["step"]), args.steps):
-        batch = next_wbatch()
-        state["params"], state["gossip"], state["opt"], metrics = step_fn(
-            state["params"], state["gossip"], state["opt"], batch,
-            jax.random.fold_in(key, step), *live_args)
-        state["step"] = jnp.int32(step + 1)
-        losses.append(float(metrics["loss"]))
-        if step % args.log_every == 0 or step == args.steps - 1:
-            extra = ""
-            if "n_good" in metrics:
-                extra = f" good_msgs={float(metrics['n_good']):.0f}"
-            print(f"step {step:5d} loss {losses[-1]:.4f}"
-                  f" ({time.time() - t0:.1f}s){extra}", flush=True)
+    with jax.sharding.set_mesh(run.mesh):
+        for step in range(int(state["step"]), args.steps):
+            batch = run.next_wbatch()
+            state["params"], state["gossip"], state["opt"], metrics = \
+                run.step_fn(state["params"], state["gossip"], state["opt"],
+                            batch, jax.random.fold_in(run.key, step),
+                            *run.live_args)
+            state["step"] = jnp.int32(step + 1)
+            losses.append(float(metrics["loss"]))
+            if step % args.log_every == 0 or step == args.steps - 1:
+                extra = ""
+                if "n_good" in metrics:
+                    extra = f" good_msgs={float(metrics['n_good']):.0f}"
+                print(f"step {step:5d} loss {losses[-1]:.4f}"
+                      f" ({time.time() - t0:.1f}s){extra}", flush=True)
 
-    # final aggregate (paper §4.3: optional MapReduce step; C5 says the
-    # first worker's model is usually just as good) — for packed-resident
-    # runs this is the ONE unpack boundary of the whole run
-    final_params = (unpack_w(state["params"], spec)
-                    if args.packed_resident else state["params"])
-    avg = final_average(final_params)
     if losses:
         print(f"final: last-loss={losses[-1]:.4f} "
               f"(start {losses[0]:.4f})", flush=True)
@@ -229,11 +252,16 @@ def main(argv=None):
               f"{int(state['step'])} >= --steps {args.steps})", flush=True)
     if args.save:
         if args.packed_resident:
-            save_checkpoint_packed(args.save, state, spec)
+            save_checkpoint_packed(args.save, state, run.spec)
         else:
             save_checkpoint(args.save, state)
         print(f"saved -> {args.save}")
     return losses
+
+
+def main(argv=None, *, devices=None):
+    enable_compile_cache()
+    return train(setup(argv, devices=devices))
 
 
 if __name__ == "__main__":

@@ -16,13 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 
 def _ambient_axis_names():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return ()
-    if mesh is None:
-        return ()
-    return tuple(mesh.axis_names or ())
+    return tuple(jax.sharding.get_abstract_mesh().axis_names)
 
 
 def _axis_ok(a, names):
@@ -34,14 +28,10 @@ def _axis_ok(a, names):
 
 
 def constrain(x, *spec):
-    """Best-effort with_sharding_constraint; no-op without an ambient mesh."""
+    """with_sharding_constraint over the axes of ``spec`` that the ambient
+    mesh has; a no-op without an ambient mesh."""
     names = _ambient_axis_names()
-    if not names:
-        return x
     clean = tuple(a if _axis_ok(a, names) else None for a in spec)
     if all(a is None for a in clean):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*clean))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*clean))

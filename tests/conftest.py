@@ -2,28 +2,19 @@
 
 NOTE: XLA_FLAGS device-count forcing is deliberately NOT set here — smoke
 tests and benchmarks must see the single real CPU device. Tests that need a
-multi-device mesh live in tests/multidevice/ which has its own conftest
-setting 8 fake devices via an early os.environ write.
+multi-device mesh run a script in a child process (``run_cpu_script``) that
+forces 8 fake devices with an early os.environ write.
 """
-import importlib.util
+import os
 import pathlib
+import subprocess
 import sys
-
-if importlib.util.find_spec("hypothesis") is None:
-    # container image without hypothesis: register the deterministic stub
-    # (tests/_hypothesis_stub.py) so `from hypothesis import ...` works.
-    # Loaded by path — the `tests` package itself is not importable under
-    # the bare `pytest` entry point (no __init__.py, repo root off sys.path).
-    _stub_path = pathlib.Path(__file__).parent / "_hypothesis_stub.py"
-    _spec = importlib.util.spec_from_file_location("hypothesis", _stub_path)
-    _hypothesis_stub = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_hypothesis_stub)
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
 
 import jax
 import numpy as np
 import pytest
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def pytest_configure(config):
@@ -41,3 +32,19 @@ def rng():
 @pytest.fixture()
 def key():
     return jax.random.key(0)
+
+
+@pytest.fixture()
+def run_cpu_script():
+    """Run a Python script in a child process, with the repo's ``src`` on
+    its path and JAX held to the CPU backend: a child can never share an
+    accelerator that its parent's process holds.  Returns the
+    CompletedProcess (text stdout/stderr); ``env`` adds variables."""
+    def run(script: str, timeout: float, env=None):
+        env = {"PYTHONPATH": str(REPO / "src"), "JAX_PLATFORMS": "cpu",
+               **{k: os.environ[k] for k in ("PATH", "HOME", "TMPDIR")
+                  if k in os.environ}, **(env or {})}
+        return subprocess.run([sys.executable, "-c", script],
+                              capture_output=True, text=True, env=env,
+                              cwd=REPO, timeout=timeout)
+    return run

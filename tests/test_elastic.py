@@ -14,8 +14,6 @@ masked ppermute payload is DROPPED, not blended, and the dead worker's
 shard stays frozen mid-run.
 """
 import dataclasses
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -543,9 +541,9 @@ ELASTIC_MESH_SCRIPT = textwrap.dedent("""
     from repro.core.gossip import (GossipConfig, asgd_gossip_apply_packed,
                                    init_packed_gossip_state, leaf_groups)
     from repro.core.packing import pack_spec_w, pack_w
-    from repro.launch.mesh import _auto_mesh, shard_map_gossip_round
+    from repro.launch.mesh import make_host_mesh, shard_map_gossip_round
 
-    mesh = _auto_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     W = 8   # oversubscribed: W_local = 2 -> the two-ppermute roll path
     ks = jax.random.split(jax.random.key(0), 2)
     params = {"a": jax.random.normal(ks[0], (W, 20, 30)),
@@ -615,15 +613,12 @@ ELASTIC_MESH_SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_shard_map_elastic_round_kills_rank_mid_run():
+def test_shard_map_elastic_round_kills_rank_mid_run(run_cpu_script):
     """8-fake-device subprocess: the manual-region elastic round under a
     mid-run kill/revive reproduces the GSPMD elastic engine exactly —
     the masked ppermute payload is DROPPED at the receiver (gate
     closed), the dead worker's shard stays bitwise frozen, and the
     revived worker re-enters after the delay window."""
-    r = subprocess.run(
-        [sys.executable, "-c", ELASTIC_MESH_SCRIPT], capture_output=True,
-        text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                        "HOME": "/root"}, cwd="/root/repo", timeout=900)
+    r = run_cpu_script(ELASTIC_MESH_SCRIPT, timeout=900)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "ELASTIC-MESH-OK" in r.stdout

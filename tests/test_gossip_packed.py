@@ -8,8 +8,6 @@ packed train step, and (subprocess, 8 fake devices) the manual-region
 ppermute exchange of launch.mesh.shard_map_gossip_round against the GSPMD
 jnp.roll formulation.
 """
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -517,9 +515,9 @@ PPERMUTE_SCRIPT = textwrap.dedent("""
                                    leaf_groups, packed_row_ranges)
     from repro.core.packing import pack_spec_w, pack_w
     from repro.kernels.gossip_blend import gossip_blend_w_resident
-    from repro.launch.mesh import _auto_mesh, shard_map_gossip_round
+    from repro.launch.mesh import make_host_mesh, shard_map_gossip_round
 
-    mesh = _auto_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     W = 8   # oversubscribed: W_local = 2 -> the two-ppermute roll path
     ks = jax.random.split(jax.random.key(0), 2)
     params = {"a": jax.random.normal(ks[0], (W, 20, 30)),
@@ -566,14 +564,11 @@ PPERMUTE_SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_shard_map_gossip_round_matches_gspmd_roll():
+def test_shard_map_gossip_round_matches_gspmd_roll(run_cpu_script):
     """8-fake-device subprocess: the manual-region exchange+blend
     (ppermute + resident kernel inside ONE shard_map) reproduces the GSPMD
     jnp.roll exchange and the single-shard resident blend, for every
     static shift and partition."""
-    r = subprocess.run(
-        [sys.executable, "-c", PPERMUTE_SCRIPT], capture_output=True,
-        text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                        "HOME": "/root"}, cwd="/root/repo", timeout=600)
+    r = run_cpu_script(PPERMUTE_SCRIPT, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "PPERMUTE-ROUND-OK" in r.stdout

@@ -14,8 +14,6 @@ round: ppermute parity vs the GSPMD engine, the collective confined to
 the initiate region, and a communication-free consume region.
 """
 import json
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -745,11 +743,11 @@ PIPELINED_MESH_SCRIPT = textwrap.dedent("""
                                    init_pipelined_gossip_state,
                                    initiate_exchange_packed, leaf_groups)
     from repro.core.packing import pack_spec_w, pack_w
-    from repro.launch.mesh import (_auto_mesh, shard_map_consume_blend,
+    from repro.launch.mesh import (make_host_mesh, shard_map_consume_blend,
                                    shard_map_initiate_exchange,
                                    shard_map_pipelined_round)
 
-    mesh = _auto_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     W = 8   # oversubscribed: W_local = 2 -> the two-ppermute roll path
     ks = jax.random.split(jax.random.key(0), 2)
     params = {"a": jax.random.normal(ks[0], (W, 20, 30)),
@@ -829,15 +827,12 @@ PIPELINED_MESH_SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_shard_map_pipelined_round_matches_gspmd():
+def test_shard_map_pipelined_round_matches_gspmd(run_cpu_script):
     """8-fake-device subprocess (W_local=2, both wire formats): the
     manual-region pipelined round reproduces the GSPMD pipelined engine;
     the initiate region carries the collective-permute (int8 payload on
     the int8 wire) and the consume region lowers with NO collective —
     the structural overlap proof."""
-    r = subprocess.run(
-        [sys.executable, "-c", PIPELINED_MESH_SCRIPT], capture_output=True,
-        text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                        "HOME": "/root"}, cwd="/root/repo", timeout=900)
+    r = run_cpu_script(PIPELINED_MESH_SCRIPT, timeout=900)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "PIPELINED-MESH-OK" in r.stdout

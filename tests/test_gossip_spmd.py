@@ -6,8 +6,6 @@ sharded-lowering properties (collective-permute only, no all-gather of
 model-sharded leaves) run in a subprocess with 8 fake devices so the main
 pytest process keeps the default 1-device view.
 """
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -220,12 +218,12 @@ SHARD_MAP_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.launch.mesh import (_auto_mesh, local_worker_count,
+    from repro.launch.mesh import (local_worker_count, make_host_mesh,
                                    n_worker_groups, shard_map_workers)
     from repro.kernels.gossip_blend import gossip_blend_worker_batched
     from repro.core.packing import pack_spec_w, pack_w
 
-    mesh = _auto_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     assert n_worker_groups(mesh) == 4
     assert local_worker_count(mesh, 8) == 2
 
@@ -271,14 +269,11 @@ SHARD_MAP_SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_shard_map_worker_batched_kernel():
+def test_shard_map_worker_batched_kernel(run_cpu_script):
     """8-fake-device subprocess: the worker-batched Pallas blend under
     shard_map_workers (each data shard blends its 2 local worker replicas)
     matches the single-shard kernel result."""
-    r = subprocess.run(
-        [sys.executable, "-c", SHARD_MAP_SCRIPT], capture_output=True,
-        text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                        "HOME": "/root"}, cwd="/root/repo", timeout=600)
+    r = run_cpu_script(SHARD_MAP_SCRIPT, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "SHARD-MAP-OK" in r.stdout
 
@@ -292,11 +287,8 @@ SPMD_SCRIPT = textwrap.dedent("""
     from repro.core.gossip import GossipConfig, init_gossip_state, asgd_gossip_apply
     from repro.core.asgd import ASGDConfig
 
-    try:  # AxisType appeared in newer jax; 0.4.x meshes are Auto already
-        mesh = jax.make_mesh((4, 2), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    except (AttributeError, TypeError):
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     W = 4
     params = {"a": jnp.ones((W, 16, 8)), "b": jnp.zeros((W, 6)),
               "c": jnp.ones((W, 8, 4))}
@@ -329,12 +321,9 @@ SPMD_SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_spmd_lowering_collective_permute_only():
+def test_spmd_lowering_collective_permute_only(run_cpu_script):
     """8-fake-device subprocess: gossip -> collective-permute, never an
     all-gather of a model-sharded param leaf."""
-    r = subprocess.run(
-        [sys.executable, "-c", SPMD_SCRIPT], capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, cwd="/root/repo", timeout=600)
+    r = run_cpu_script(SPMD_SCRIPT, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "SPMD-OK" in r.stdout

@@ -11,8 +11,6 @@ int8-aware packed checkpoint boundary (scales transient, never written),
 and (subprocess, 8 fake devices, slow) the manual-region int8 ppermute
 exchange of launch.mesh.shard_map_gossip_round against the GSPMD engine.
 """
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -481,9 +479,9 @@ INT8_PPERMUTE_SCRIPT = textwrap.dedent("""
                                    packed_row_ranges)
     from repro.core.packing import pack_spec_w, pack_w
     from repro.kernels.gossip_blend import gossip_blend_w_resident
-    from repro.launch.mesh import _auto_mesh, shard_map_gossip_round
+    from repro.launch.mesh import make_host_mesh, shard_map_gossip_round
 
-    mesh = _auto_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     W = 8   # oversubscribed: W_local = 2 -> the two-ppermute roll path
     ks = jax.random.split(jax.random.key(0), 2)
     params = {"a": jax.random.normal(ks[0], (W, 20, 30)),
@@ -542,15 +540,12 @@ INT8_PPERMUTE_SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_shard_map_int8_round_matches_gspmd():
+def test_shard_map_int8_round_matches_gspmd(run_cpu_script):
     """8-fake-device subprocess: the manual-region int8 exchange+blend
     (quantize -> int8 ppermute + scales -> fused-dequant resident kernel,
     all inside ONE shard_map) reproduces the GSPMD quantized roll and the
     single-shard kernel, and the step==0 staleness guard holds inside the
     manual region."""
-    r = subprocess.run(
-        [sys.executable, "-c", INT8_PPERMUTE_SCRIPT], capture_output=True,
-        text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                        "HOME": "/root"}, cwd="/root/repo", timeout=600)
+    r = run_cpu_script(INT8_PPERMUTE_SCRIPT, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "INT8-PPERMUTE-OK" in r.stdout

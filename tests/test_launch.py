@@ -1,7 +1,5 @@
 """Launch-layer tests: sharding spec selection, input specs, and a
 small-mesh dry-run smoke (subprocess with 8 fake devices)."""
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -122,12 +120,9 @@ DRYRUN_SCRIPT = textwrap.dedent("""
     mesh = make_host_mesh(data=4, model=2)
     fn, specs = ST.step_and_args(cfg, shape, mesh, GossipConfig(
         shifts=(1, 2), partial_blocks=2))
-    from repro.launch.mesh import mesh_context
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(fn).lower(*specs.values()).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        cost = cost[0]
     assert cost.get("flops", 0) > 0
     assert "collective-permute" in compiled.as_text()
     print("DRYRUN-SMOKE-OK")
@@ -135,10 +130,73 @@ DRYRUN_SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_dryrun_smoke_small_mesh():
-    r = subprocess.run(
-        [sys.executable, "-c", DRYRUN_SCRIPT], capture_output=True,
-        text=True, cwd="/root/repo", timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"})
+def test_dryrun_smoke_small_mesh(run_cpu_script):
+    r = run_cpu_script(DRYRUN_SCRIPT, timeout=900)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "DRYRUN-SMOKE-OK" in r.stdout
+
+
+TRAIN_MESH_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.train import setup, train
+
+    argv = ["--arch", "smollm-135m", "--reduced", "--steps", "4",
+            "--workers", "4", "--batch", "1", "--seq", "32",
+            "--wire-format", "int8", "--pipelined", "--log-every", "100"]
+    spread = setup(argv)
+    assert dict(spread.mesh.shape) == {"data": 4, "model": 1}
+    assert spread.state["params"].sharding.spec == P("data")
+    # stacked pipelined FIFO: the worker axis is its second
+    assert spread.state["gossip"].buf.sharding.spec == P(None, "data")
+    losses4 = train(spread)
+    losses1 = train(setup(argv, devices=jax.devices()[:1]))
+    np.testing.assert_allclose(losses4, losses1, rtol=1e-5)
+    print("TRAIN-MESH-OK")
+""")
+
+
+def test_trainer_splits_workers_over_devices(run_cpu_script):
+    """4 fake devices: the trainer places one worker per device and runs
+    the packed kernel per device (shard_map); the losses match the same
+    run with every worker on one device."""
+    r = run_cpu_script(TRAIN_MESH_SCRIPT, timeout=900)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "TRAIN-MESH-OK" in r.stdout
+
+
+CACHE_SCRIPT = textwrap.dedent("""
+    import jax, jax.numpy as jnp
+    from repro.launch.cache import enable_compile_cache
+    print(enable_compile_cache())
+    # cache even a tiny compile (steering for the test only)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+""")
+
+
+def test_compile_cache_follows_env(run_cpu_script, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: entries land there and the helper
+    sets nothing; unset: the fixed <repo>/.jax_cache."""
+    from repro.launch.cache import REPO_CACHE_DIR, enable_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    r = run_cpu_script(CACHE_SCRIPT, timeout=300,
+                       env={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.split()[0] == str(tmp_path)
+    assert any(tmp_path.iterdir())
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == old
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        assert (REPO_CACHE_DIR.parent / "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

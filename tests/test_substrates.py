@@ -128,23 +128,23 @@ class TestTrainerIntegration:
     def test_lm_training_reduces_loss(self):
         """End-to-end: ASGD-train a reduced smollm for 40 steps; next-token
         loss must decrease materially (synthetic data has structure)."""
-        from repro.launch.train import main as train_main
-        losses = train_main([
+        from repro.launch.train import setup, train
+        losses = train(setup([
             "--arch", "smollm-135m", "--reduced", "--steps", "40",
             "--workers", "2", "--batch", "2", "--seq", "64",
-            "--eps", "0.1", "--log-every", "100"])
+            "--eps", "0.1", "--log-every", "100"]))
         assert losses[-1] < losses[0] * 0.85, (losses[0], losses[-1])
 
     def test_checkpoint_resume_continues(self, tmp_path):
-        from repro.launch.train import main as train_main
+        from repro.launch.train import setup, train
         ck = str(tmp_path / "t.msgpack")
-        train_main(["--arch", "smollm-135m", "--reduced", "--steps", "6",
-                    "--workers", "2", "--batch", "1", "--seq", "32",
-                    "--save", ck, "--log-every", "100"])
-        losses = train_main(
+        train(setup(["--arch", "smollm-135m", "--reduced", "--steps", "6",
+                     "--workers", "2", "--batch", "1", "--seq", "32",
+                     "--save", ck, "--log-every", "100"]))
+        losses = train(setup(
             ["--arch", "smollm-135m", "--reduced", "--steps", "10",
              "--workers", "2", "--batch", "1", "--seq", "32",
-             "--restore", ck, "--log-every", "100"])
+             "--restore", ck, "--log-every", "100"]))
         assert len(losses) == 4  # resumed at step 6, ran to 10
 
     def test_serve_generates(self):
