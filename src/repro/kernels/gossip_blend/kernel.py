@@ -173,6 +173,7 @@ def gossip_reduce_pallas(w2d, dw2d, ext3d, *, block_rows=64, interpret=None):
         out_specs=pl.BlockSpec(acc_shape, lambda i: (0, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="gossip_reduce_pallas",
     )(w2d, dw2d, ext3d)
     return _terms_from_partials(partials[0])
 
@@ -202,6 +203,7 @@ def gossip_apply_pallas(w2d, dw2d, ext3d, gates, inv_denom, *, eps,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(w2d.shape, w2d.dtype),
         interpret=resolve_interpret(interpret),
+        name="gossip_apply_pallas",
     )(w2d, dw2d, ext3d, gates.reshape(p, 1),
       jnp.asarray(inv_denom, jnp.float32).reshape(1, 1))
 
@@ -305,6 +307,7 @@ def gossip_reduce_w_pallas(w3d, dw3d, ext4d, mask2d=None, *, block_rows=64,
                                lambda wi, i: (wi, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="gossip_reduce_w_pallas",
     )(*operands)
     return _terms_from_partials(partials)
 
@@ -342,6 +345,7 @@ def gossip_apply_w_pallas(w3d, dw3d, ext4d, gates, inv_denom, mask2d=None, *,
         out_specs=spec_s,
         out_shape=jax.ShapeDtypeStruct(w3d.shape, w3d.dtype),
         interpret=resolve_interpret(interpret),
+        name="gossip_apply_w_pallas",
     )(*operands)
 
 
@@ -462,6 +466,7 @@ def gossip_reduce_w_resident_pallas(row_range, w3d, dw3d, ext4d,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="gossip_reduce_w_resident_pallas",
     )(row_range.astype(jnp.int32), *operands)
     return _terms_from_partials(partials)
 
@@ -509,4 +514,5 @@ def gossip_apply_w_resident_pallas(row_range, w3d, dw3d, ext4d, gates,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(w3d.shape, w3d.dtype),
         interpret=resolve_interpret(interpret),
+        name="gossip_apply_w_resident_pallas",
     )(row_range.astype(jnp.int32), *operands)

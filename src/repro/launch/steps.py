@@ -314,6 +314,11 @@ def make_train_step(cfg: ModelConfig, *, algo="asgd", inner="sgd",
     ``step -> lr`` (optim.optimizers.lr_schedule) evaluated each round
     on the gossip step counter and fed to the consume blend's per-round
     lr operand; None keeps the static acfg.eps.
+
+    Every variant runs its forward/backward under the named scope
+    ``step.fwd_bwd`` and its gossip round (the pipelined initiate and
+    consume, or the ASGD apply) under ``step.gossip``, so a device trace
+    can split the step by layer (README.md §Tracing).
     """
     from ..optim import (adam_update, momentum_update)
 
@@ -368,8 +373,9 @@ def make_train_step(cfg: ModelConfig, *, algo="asgd", inner="sgd",
             raise ValueError(
                 f"live= (peer liveness, DESIGN.md §8) requires algo='asgd' "
                 f"(got {algo!r}): sync/silent carry no gossip state to gate")
-        loss, grads = jax.vmap(jax.value_and_grad(per_worker_loss),
-                               **vmap_kw)(params, batch)
+        with jax.named_scope("step.fwd_bwd"):
+            loss, grads = jax.vmap(jax.value_and_grad(per_worker_loss),
+                                   **vmap_kw)(params, batch)
         dw, opt_state = direction(params, grads, opt_state)
         if algo == "sync":
             new_params = sync_dp_apply(params, dw, acfg.eps)
@@ -380,8 +386,9 @@ def make_train_step(cfg: ModelConfig, *, algo="asgd", inner="sgd",
             new_gossip = gossip
             metrics = {"loss": jnp.mean(loss)}
         else:
-            new_params, new_gossip, gm = asgd_gossip_apply(
-                params, dw, gossip, key, gcfg, acfg, live=live)
+            with jax.named_scope("step.gossip"):
+                new_params, new_gossip, gm = asgd_gossip_apply(
+                    params, dw, gossip, key, gcfg, acfg, live=live)
             metrics = {"loss": jnp.mean(loss), "n_good": gm["n_good"],
                        "gate": gm["gate"]}
         return new_params, new_gossip, opt_state, metrics
@@ -403,14 +410,16 @@ def make_train_step(cfg: ModelConfig, *, algo="asgd", inner="sgd",
             #    input — the ppermute shares no dependency with the
             #    forward/backward below, so it runs concurrently with it
             if not acfg.silent:
-                if live is None:
-                    sent, sent_scales, block_idx = initiate_exchange_packed(
-                        packed, key, gcfg, pack_spec)
-                    sent_live = None
-                else:
-                    sent, sent_scales, block_idx, sent_live = \
-                        initiate_exchange_packed(packed, key, gcfg,
-                                                 pack_spec, live=live)
+                with jax.named_scope("step.gossip"):
+                    if live is None:
+                        sent, sent_scales, block_idx = \
+                            initiate_exchange_packed(packed, key, gcfg,
+                                                     pack_spec)
+                        sent_live = None
+                    else:
+                        sent, sent_scales, block_idx, sent_live = \
+                            initiate_exchange_packed(packed, key, gcfg,
+                                                     pack_spec, live=live)
 
             # 2. forward/backward, differentiated w.r.t. the PACKED rows:
             #    the unpack views fuse into the consumers and the VJP
@@ -418,8 +427,9 @@ def make_train_step(cfg: ModelConfig, *, algo="asgd", inner="sgd",
             def loss_of_rows(rows2d, b):
                 return per_worker_loss(unpack_rows(rows2d, pack_spec), b)
 
-            loss, pgrads = jax.vmap(jax.value_and_grad(loss_of_rows),
-                                    **vmap_kw)(packed, batch)
+            with jax.named_scope("step.fwd_bwd"):
+                loss, pgrads = jax.vmap(jax.value_and_grad(loss_of_rows),
+                                        **vmap_kw)(packed, batch)
             dw, opt_state = direction(packed, pgrads, opt_state)
 
             if acfg.silent:
@@ -433,9 +443,10 @@ def make_train_step(cfg: ModelConfig, *, algo="asgd", inner="sgd",
 
             # 3. CONSUME: fused blend + eq.-1 update of the payload
             #    launched delay+1 rounds ago; push this round's launch
-            new_packed, new_gossip, gm = consume_exchange_packed(
-                packed, dw, gossip, sent, sent_scales, block_idx, gcfg,
-                acfg, pack_spec, lr=lr, sent_live=sent_live, live=live)
+            with jax.named_scope("step.gossip"):
+                new_packed, new_gossip, gm = consume_exchange_packed(
+                    packed, dw, gossip, sent, sent_scales, block_idx, gcfg,
+                    acfg, pack_spec, lr=lr, sent_live=sent_live, live=live)
             metrics = {"loss": jnp.mean(loss), "n_good": gm["n_good"],
                        "gate": gm["gate"]}
             return new_packed, new_gossip, opt_state, metrics
@@ -448,8 +459,9 @@ def make_train_step(cfg: ModelConfig, *, algo="asgd", inner="sgd",
                 f"live= (peer liveness, DESIGN.md §8) requires algo='asgd' "
                 f"(got {algo!r}): sync/silent carry no gossip state to gate")
         params = unpack_w(packed, pack_spec)   # views of the resident buf
-        loss, grads = jax.vmap(jax.value_and_grad(per_worker_loss),
-                               **vmap_kw)(params, batch)
+        with jax.named_scope("step.fwd_bwd"):
+            loss, grads = jax.vmap(jax.value_and_grad(per_worker_loss),
+                                   **vmap_kw)(params, batch)
         dw, opt_state = direction(params, grads, opt_state)
         pdw = pack_w(dw, pack_spec)            # the one pack per round
         if algo == "sync":
@@ -463,8 +475,10 @@ def make_train_step(cfg: ModelConfig, *, algo="asgd", inner="sgd",
             new_gossip = gossip
             metrics = {"loss": jnp.mean(loss)}
         else:
-            new_packed, new_gossip, gm = asgd_gossip_apply_packed(
-                packed, pdw, gossip, key, gcfg, acfg, pack_spec, live=live)
+            with jax.named_scope("step.gossip"):
+                new_packed, new_gossip, gm = asgd_gossip_apply_packed(
+                    packed, pdw, gossip, key, gcfg, acfg, pack_spec,
+                    live=live)
             metrics = {"loss": jnp.mean(loss), "n_good": gm["n_good"],
                        "gate": gm["gate"]}
         return new_packed, new_gossip, opt_state, metrics

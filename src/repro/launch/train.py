@@ -15,6 +15,10 @@ W / data worker replicas; on one device every replica shares it.  The
 packed engines run their Pallas kernel per device (shard_map over
 ``data``) and the gossip exchange lowers to a collective-permute.  Drop
 --reduced for the published widths.
+
+Tracing: the loop and the batch function record ``train.*`` host spans
+into ``jax.profiler``; they show in a trace whenever a profiler session
+is active (README.md §Tracing).
 """
 from __future__ import annotations
 
@@ -212,9 +216,11 @@ def setup(argv=None, *, devices=None):
         for w in range(W)]
 
     def next_wbatch():
-        bs = [next(it) for it in its]
-        return place_workers({k: np.stack([b[k] for b in bs])
-                              for k in bs[0]}, mesh)
+        with jax.profiler.TraceAnnotation("train.batch.generate"):
+            bs = [next(it) for it in its]
+            host = {k: np.stack([b[k] for b in bs]) for k in bs[0]}
+        with jax.profiler.TraceAnnotation("train.batch.place"):
+            return place_workers(host, mesh)
 
     return SimpleNamespace(args=args, cfg=cfg, spec=spec, mesh=mesh,
                            state=state, step_fn=step_fn, key=key,
@@ -229,19 +235,26 @@ def train(run):
     losses = []
     with jax.sharding.set_mesh(run.mesh):
         for step in range(int(state["step"]), args.steps):
-            batch = run.next_wbatch()
-            state["params"], state["gossip"], state["opt"], metrics = \
-                run.step_fn(state["params"], state["gossip"], state["opt"],
-                            batch, jax.random.fold_in(run.key, step),
-                            *run.live_args)
-            state["step"] = jnp.int32(step + 1)
-            losses.append(float(metrics["loss"]))
-            if step % args.log_every == 0 or step == args.steps - 1:
-                extra = ""
-                if "n_good" in metrics:
-                    extra = f" good_msgs={float(metrics['n_good']):.0f}"
-                print(f"step {step:5d} loss {losses[-1]:.4f}"
-                      f" ({time.time() - t0:.1f}s){extra}", flush=True)
+            with jax.profiler.StepTraceAnnotation("train.step",
+                                                  step_num=step):
+                batch = run.next_wbatch()
+                key = jax.random.fold_in(run.key, step)
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    out = run.step_fn(state["params"], state["gossip"],
+                                      state["opt"], batch, key,
+                                      *run.live_args)
+                state["params"], state["gossip"], state["opt"], metrics = out
+                state["step"] = jnp.int32(step + 1)
+                with jax.profiler.TraceAnnotation("train.host_read"):
+                    losses.append(float(metrics["loss"]))
+                if step % args.log_every == 0 or step == args.steps - 1:
+                    extra = ""
+                    if "n_good" in metrics:
+                        with jax.profiler.TraceAnnotation("train.host_read"):
+                            n_good = float(metrics["n_good"])
+                        extra = f" good_msgs={n_good:.0f}"
+                    print(f"step {step:5d} loss {losses[-1]:.4f}"
+                          f" ({time.time() - t0:.1f}s){extra}", flush=True)
 
     if losses:
         print(f"final: last-loss={losses[-1]:.4f} "
@@ -251,10 +264,11 @@ def train(run):
         print(f"final: no steps run (restored step "
               f"{int(state['step'])} >= --steps {args.steps})", flush=True)
     if args.save:
-        if args.packed_resident:
-            save_checkpoint_packed(args.save, state, run.spec)
-        else:
-            save_checkpoint(args.save, state)
+        with jax.profiler.TraceAnnotation("train.save"):
+            if args.packed_resident:
+                save_checkpoint_packed(args.save, state, run.spec)
+            else:
+                save_checkpoint(args.save, state)
         print(f"saved -> {args.save}")
     return losses
 

@@ -11,6 +11,7 @@ The topology is described inside a fixture, never while a module is
 imported: one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,3 +140,9 @@ def test_pipelined_round_partitions_over_four_chips(topo, smollm_spec,
     hlo = compiled.as_text()
     assert "collective-permute" in hlo
     assert "tpu_custom_call" in hlo
+    # the device trace names each pass by its instruction, which takes the
+    # pallas_call's name: the benchmark's rooflines find them by it
+    for name in ("gossip_reduce_w_resident_pallas",
+                 "gossip_apply_w_resident_pallas"):
+        assert re.search(rf"^\s*%{name}(\.\d+)? = .*custom-call", hlo,
+                         re.M), name
