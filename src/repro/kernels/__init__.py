@@ -12,6 +12,9 @@ Kernels (DESIGN.md §6):
   gossip_blend  — batched fused ASGD update: P externals per gossip round,
                   all gates + the gated mean in two HBM passes.
   ssd_scan      — mamba-2 chunked SSD inner scan.
+  flash_attention — causal attention, forward and backward, with the
+                  score tiles in VMEM (JAX's Pallas TPU kernels, bound
+                  to this convention).
 
 ``interpret`` convention: every public kernel entry point takes
 ``interpret=None`` meaning "auto" — run the Pallas interpreter only when no

@@ -17,9 +17,11 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels.flash_attention import supports as flash_kernel_supports
 from .common import (AttnSpec, attention_decode, attention_dense,
-                     attention_flash, causal_mask, init_attention,
-                     init_kv_cache, make_norm, prefix_mask, sliding_mask)
+                     attention_flash, attention_flash_kernel, causal_mask,
+                     init_attention, init_kv_cache, make_norm, prefix_mask,
+                     sliding_mask)
 from .mlp import apply_mlp, apply_mlp_nonglu, init_mlp, init_mlp_nonglu
 from .moe import apply_moe, apply_moe_decode, init_moe
 from .rglru import (apply_rglru, apply_rglru_decode, init_rglru,
@@ -132,6 +134,7 @@ def _ffn(cfg, p, x, norm):
     return x, jnp.float32(0.0)
 
 
+@jax.named_scope("model.attention")
 def _attend_full(cfg, spec, p_attn, h, positions, ltype, prefix_len):
     from .hints import constrain
     S = h.shape[1]
@@ -141,10 +144,25 @@ def _attend_full(cfg, spec, p_attn, h, positions, ltype, prefix_len):
     if batch_shard:
         h = constrain(h, "model", None, None)
     if S >= FLASH_MIN_SEQ and S % 512 == 0:
-        out = attention_flash(
-            p_attn, spec, h, positions,
-            window=window,
-            prefix_len=prefix_len if ltype == "G" or window is None else None)
+        def scan_path(p, x, pos):
+            return attention_flash(
+                p, spec, x, pos, window=window,
+                prefix_len=(prefix_len if ltype == "G" or window is None
+                            else None))
+
+        def kernel_path(p, x, pos):
+            return attention_flash_kernel(p, spec, x, pos, interpret=False)
+
+        if (ltype != "E" and window is None and not prefix_len
+                and spec.softcap is None
+                and flash_kernel_supports(S, spec.head_dim)):
+            # plain causal: the fused kernels where the step is lowered
+            # for a TPU, the jnp scan everywhere else
+            out = jax.lax.platform_dependent(p_attn, h, positions,
+                                             tpu=kernel_path,
+                                             default=scan_path)
+        else:
+            out = scan_path(p_attn, h, positions)
         if batch_shard:
             out = constrain(out, "model", None, None)
         return out

@@ -3,7 +3,7 @@
 Pure-JAX (no flax): params are nested dicts of jnp arrays; every block is a
 pair of functions ``init_*(key, cfg...) -> params`` and ``apply(params, x)``.
 
-Attention comes in three execution forms:
+Attention comes in four execution forms:
   * ``attention_dense``  — materializes (S, S) scores; smoke-test scale only.
   * ``attention_flash``  — chunked online-softmax (scan over KV blocks inside
     a scan over Q blocks); O(block_q * block_k) live memory. This is the
@@ -11,6 +11,8 @@ Attention comes in three execution forms:
     softmax(QK^T)V into a flash pattern by itself, and a materialized
     32768^2 score tensor is ~4GB/head — the dry-run memory analysis gates
     this (see EXPERIMENTS.md §Dry-run).
+  * ``attention_flash_kernel`` — the same for plain causal layers on a TPU,
+    in fused Pallas kernels (kernels/flash_attention).
   * ``attention_decode`` — one query position against a KV cache.
 
 GQA throughout: n_kv_heads <= n_heads, queries grouped onto KV heads.
@@ -25,6 +27,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from ..kernels.flash_attention import flash_attention
 
 # ---------------------------------------------------------------------------
 # initializers
@@ -211,8 +215,10 @@ def attention_flash(params, spec: AttnSpec, x, positions, *,
     Scans over Q blocks; inside, scans over KV blocks with running
     (max, sum, acc) online-softmax state. Sliding-window layers skip KV
     blocks wholly outside the window via masking (XLA hoists the band
-    structure after unrolling the block mask — the Pallas kernel tightens
-    this further on real hardware).
+    structure after unrolling the block mask). On a TPU, plain causal
+    layers take :func:`attention_flash_kernel` instead (the dispatch is
+    ``blocks._attend_full``); this path serves the CPU, sliding windows,
+    prefix masks and softcapped scores.
     """
     B, S, D = x.shape
     q, k, v = _project_qkv(params, spec, x, positions)
@@ -271,6 +277,18 @@ def attention_flash(params, spec: AttnSpec, x, positions, *,
                        (jnp.arange(nq), qb))                   # (nq,B,H,bq,Dh)
     out = outs.transpose(1, 0, 3, 2, 4).reshape(B, S, H, Dh)
     return jnp.einsum("bqhk,hkd->bqd", out, params["wo"])
+
+
+def attention_flash_kernel(params, spec: AttnSpec, x, positions, *,
+                           interpret=None):
+    """Plain causal attention through the fused Pallas flash kernels
+    (kernels/flash_attention): the score and probability tiles stay in
+    VMEM, and KV blocks above the diagonal are skipped. Masks by index, so
+    ``positions`` must count 0..S-1, as the full-sequence forward's do."""
+    q, k, v = _project_qkv(params, spec, x, positions)
+    out = flash_attention(q, _gqa_expand(k, spec.n_heads),
+                          _gqa_expand(v, spec.n_heads), interpret=interpret)
+    return jnp.einsum("bqhk,hkd->bqd", out.astype(x.dtype), params["wo"])
 
 
 # ---------------------------------------------------------------------------

@@ -146,3 +146,98 @@ def test_pipelined_round_partitions_over_four_chips(topo, smollm_spec,
                  "gossip_apply_w_resident_pallas"):
         assert re.search(rf"^\s*%{name}(\.\d+)? = .*custom-call", hlo,
                          re.M), name
+
+
+def test_attention_kernels_compile_at_real_width(one_chip, no_compile_cache):
+    """smollm-135m's attention layer as the train step runs it (W replicas
+    under vmap, the layer under checkpoint, S 2048), forward and backward:
+    lowered for the chip, the layer takes the fused kernels, and no loop of
+    the jnp scan is left."""
+    from repro.kernels.flash_attention import block_sizes
+    from repro.models import blocks
+    from repro.models.common import init_attention
+
+    cfg = get_arch("smollm-135m")
+    spec = blocks.attn_spec(cfg)
+    S = 2048
+    assert (spec.n_heads, spec.n_kv_heads, spec.head_dim) == (9, 3, 64)
+    pos = jnp.broadcast_to(jnp.arange(S), (1, S))
+
+    def loss(p, h):
+        layer = jax.checkpoint(lambda p, h: blocks._attend_full(
+            cfg, spec, p, h, pos, "G", 0))
+        return jnp.sum(jax.vmap(layer, in_axes=(None, 0))(p, h) ** 2)
+
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_attention(jax.random.key(0), spec)))
+    h = jax.ShapeDtypeStruct((W, 1, S, cfg.d_model), jnp.float32,
+                             sharding=one_chip)
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile().as_text()
+    bs = block_sizes(S, spec.head_dim)
+    kernels = ("flash_mha_fwd",
+               f"flash_mha_bwd_dkv_block_q_major_{bs.block_q_major_dkv}",
+               f"flash_mha_bwd_dq_block_q_major_{bs.block_q_dq}")
+    for name in kernels:
+        assert re.search(rf"^\s*%[\w.]*{name}[\w.]* = .*tpu_custom_call",
+                         hlo, re.M), name
+    assert not re.search(r"\bwhile\(", hlo)
+
+
+@pytest.mark.parametrize("seq,heads,head_dim", [(4096, 8, 128),
+                                                (2048, 4, 256)])
+def test_attention_kernels_compile_for_wider_heads(one_chip, no_compile_cache,
+                                                   seq, heads, head_dim):
+    """The other plain-causal models' heads (qwen, phi-3.5-moe: 128;
+    gemma-3's global layers: 256) fit the kernels' tiles in VMEM."""
+    from repro.kernels.flash_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct((1, seq, heads, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, interpret=False)
+                       .astype(jnp.float32))
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    for name in ("flash_mha_fwd", "flash_mha_bwd_dkv", "flash_mha_bwd_dq"):
+        assert re.search(rf"^\s*%[\w.]*{name}[\w.]* = .*tpu_custom_call",
+                         hlo, re.M), name
+
+
+def test_attention_kernels_run_per_chip_on_a_worker_split(topo,
+                                                          no_compile_cache):
+    """With the workers split over four chips (the train step's vmap over
+    workers names the mesh's data axis), each chip runs the kernels on its
+    own worker: no all-gather, where GSPMD alone refuses the Mosaic call."""
+    from repro.models import blocks
+    from repro.models.common import init_attention
+
+    cfg = get_arch("smollm-135m")
+    spec = blocks.attn_spec(cfg)
+    S = 2048
+    mesh = make_host_mesh(data=4, model=1, devices=topo.devices)
+    rep = NamedSharding(mesh, PartitionSpec())
+    pos = jnp.broadcast_to(jnp.arange(S), (1, S))
+
+    def loss(p, h):
+        layer = jax.checkpoint(lambda p, h: blocks._attend_full(
+            cfg, spec, p, h, pos, "G", 0))
+        return jnp.sum(jax.vmap(layer, in_axes=(None, 0),
+                                spmd_axis_name="data")(p, h) ** 2)
+
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep),
+        jax.eval_shape(lambda: init_attention(jax.random.key(0), spec)))
+    h = jax.ShapeDtypeStruct((W, 1, S, cfg.d_model), jnp.float32,
+                             sharding=NamedSharding(mesh,
+                                                    PartitionSpec("data")))
+    with jax.sharding.set_mesh(mesh):
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, h).compile().as_text()
+    fwd = re.search(r"^\s*%[\w.]*flash_mha_fwd[\w.]* = \(bf16\[([\d,]+)\]",
+                    hlo, re.M)
+    assert fwd and fwd.group(1) == f"1,{spec.n_heads},{S},{spec.head_dim}"
+    assert "flash_mha_bwd_dkv" in hlo and "flash_mha_bwd_dq" in hlo
+    assert not re.search(r"\ball-gather\(", hlo)
